@@ -74,6 +74,9 @@ def engine_curve(gen_len: int = 4, repeats: int = 3) -> List[Dict]:
 
 
 def run(quick: bool = False) -> List[Dict]:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows = model_curves()
     rows += engine_curve(repeats=1 if quick else 3)
     write_csv("fig3_fig4_batch_scaling.csv", rows)

@@ -176,6 +176,9 @@ def packing_rows(quick: bool) -> List[Dict]:
 
 
 def run(quick: bool = False) -> List[Dict]:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows = decode_rows(quick)
     rows += packing_rows(quick)
     write_csv("engine.csv", rows)

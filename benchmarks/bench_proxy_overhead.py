@@ -103,6 +103,9 @@ def fleet_controller_throughput(n_endpoints: int = 4096,
 
 
 def run(quick: bool = False) -> List[Dict]:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     n = 20_000 if quick else 50_000
     base, traced, overhead_pct = tracing_overhead(n)
     rows = [

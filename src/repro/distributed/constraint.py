@@ -8,7 +8,6 @@ tests) or partial meshes (debug runs).
 """
 from __future__ import annotations
 
-import warnings
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
@@ -18,19 +17,8 @@ AxisSpec = Union[None, str, Tuple[str, ...]]
 
 
 def ambient_mesh():
-    """Mesh of the enclosing ``with mesh:`` / ``set_mesh`` scope, or None."""
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            return am
-    except Exception:
-        pass
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        try:
-            mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
+    """Abstract mesh of the enclosing ``jax.set_mesh`` scope, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 

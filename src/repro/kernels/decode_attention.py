@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -33,7 +32,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     k_start = ki * block_k
     # skip blocks entirely past the valid cache region
     @pl.when(k_start < length)
@@ -63,7 +62,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def decode_attention_bhgd(q, k, v, lengths, *, block_k: int = 512,
-                          interpret: bool = True):
+                          interpret: bool):
     """Decode attention over pre-flattened kv-heads.
 
     q: (BHkv, G, D) — one token's query heads grouped by kv head;
@@ -84,7 +83,9 @@ def decode_attention_bhgd(q, k, v, lengths, *, block_k: int = 512,
         kernel,
         grid=(bh, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, ki: (b,), memory_space=pltpu.SMEM),
+            # The whole (BHkv,) lengths vector sits in SMEM: Mosaic refuses a
+            # rank-1 block narrower than 128 that is not the whole array.
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, g, d), lambda b, ki: (b, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
@@ -96,7 +97,7 @@ def decode_attention_bhgd(q, k, v, lengths, *, block_k: int = 512,
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), q, k, v)

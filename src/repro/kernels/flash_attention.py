@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -76,7 +75,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True):
+                         block_k: int = 128, interpret: bool):
     """Flash attention over pre-flattened heads.
 
     q: (BHq, Sq, D); k, v: (BHkv, Sk, D) with BHq = BHkv · G.
@@ -118,7 +117,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, block_q: int = 128,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

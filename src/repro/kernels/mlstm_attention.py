@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -80,7 +79,7 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, fcum_ref, logi_ref, o_ref,
 
 
 def mlstm_attention_bhsd(q, k, v, log_i, log_f, *, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True):
+                         block_k: int = 128, interpret: bool):
     """Parallel mLSTM over pre-flattened heads.
 
     q/k/v: (BH, S, D); log_i, log_f: (BH, S) (input-gate log and
@@ -125,7 +124,7 @@ def mlstm_attention_bhsd(q, k, v, log_i, log_f, *, block_q: int = 128,
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, fcum_q[..., None], fk_li[..., None])

@@ -1,17 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These accept model-layout tensors — q (B, S, Hq, D), caches
-(B, S, Hkv, D), SSD inputs (B, S, H, P) — handle GQA head-flattening,
-padding, and dtype plumbing, and fall back to interpret mode off-TPU
-(``interpret=None`` → auto: real Mosaic lowering on TPU, Python
-interpretation on CPU so the same call sites work everywhere).
+(B, S, Hkv, D), SSD inputs (B, S, H, P) — and handle GQA head-flattening,
+padding, and dtype plumbing. Kernels lower through Mosaic for the TPU
+unless the caller passes ``interpret=True`` (Pallas interpret mode, which
+is how CPU tests run them). There is no automatic fallback: on a backend
+that is not a TPU, a call without ``interpret=True`` fails to lower.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import decode_attention as _dec
@@ -19,14 +16,8 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
 
 
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
+                    block_k: int = 128, interpret: bool = False):
     """q: (B, S, Hq, D); k, v: (B, S, Hkv, D) → (B, S, Hq, D)."""
     b, s, hq, d = q.shape
     hkv = k.shape[2]
@@ -35,12 +26,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     vf = v.transpose(0, 2, 1, 3).reshape(b * hkv, s, d)
     out = _fa.flash_attention_bhsd(
         qf, kf, vf, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=_auto_interpret(interpret))
+        interpret=interpret)
     return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
-                     interpret: Optional[bool] = None):
+                     interpret: bool = False):
     """q: (B, 1, Hq, D); caches: (B, S, Hkv, D); cache_len: scalar or (B,).
 
     Returns (B, 1, Hq, D) — drop-in for the jnp decode path.
@@ -56,12 +47,12 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, block_k: int = 512,
     lengths = jnp.repeat(lengths, hkv) if lengths.shape[0] == b else lengths
     out = _dec.decode_attention_bhgd(
         qf, kf, vf, lengths, block_k=block_k,
-        interpret=_auto_interpret(interpret))
+        interpret=interpret)
     return out.reshape(b, hkv, g, d).reshape(b, 1, hq, d)
 
 
 def mlstm_attention(q, k, v, log_i, log_f, *, block_q: int = 128,
-                    block_k: int = 128, interpret: Optional[bool] = None):
+                    block_k: int = 128, interpret: bool = False):
     """Parallel mLSTM in model layout.
 
     q/k/v: (B, S, H, D); log_i, log_f: (B, S, H) → y: (B, S, H, D).
@@ -76,12 +67,12 @@ def mlstm_attention(q, k, v, log_i, log_f, *, block_q: int = 128,
     lf = log_f.transpose(0, 2, 1).reshape(b * h, s)
     out = _ml.mlstm_attention_bhsd(
         flat(q), flat(k), flat(v), li, lf, block_q=block_q, block_k=block_k,
-        interpret=_auto_interpret(interpret))
+        interpret=interpret)
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
 def ssd_scan(x, dt, a, b, c, *, chunk: int = 128,
-             interpret: Optional[bool] = None):
+             interpret: bool = False):
     """SSD scan in model layout.
 
     x: (B, S, H, P); dt: (B, S, H); a: (H,); b, c: (B, S, N) →
@@ -93,5 +84,5 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int = 128,
     dtf = dt.transpose(0, 2, 1).reshape(bsz * h, s)
     af = jnp.tile(a.reshape(1, h), (bsz, 1)).reshape(bsz * h)
     out = _ssd.ssd_scan_bhsd(xf, dtf, af, b, c, chunk=chunk,
-                             interpret=_auto_interpret(interpret))
+                             interpret=interpret)
     return out.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)

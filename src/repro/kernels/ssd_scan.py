@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -69,7 +68,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
     h_ref[...] = h_prev * jnp.exp(da_total) + inject
 
 
-def ssd_scan_bhsd(x, dt, a, b, c, *, chunk: int = 128, interpret: bool = True):
+def ssd_scan_bhsd(x, dt, a, b, c, *, chunk: int = 128, interpret: bool):
     """SSD scan over pre-flattened heads.
 
     x: (BH, S, P); dt: (BH, S) (positive, already softplus'd);
@@ -105,7 +104,7 @@ def ssd_scan_bhsd(x, dt, a, b, c, *, chunk: int = 128, interpret: bool = True):
         out_specs=pl.BlockSpec((1, chunk, p), lambda bh_, ci: (bh_, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, nc * chunk, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, a.astype(jnp.float32), b, c)

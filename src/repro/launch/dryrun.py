@@ -163,7 +163,7 @@ def _compile_cell(cfg: ModelConfig, shape: ShapeCell, mesh,
             shape.global_batch))
         out_sh = (logits_sh, cache_sh)
     t0 = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh).lower(
             *abstract_args)
         t_lower = time.perf_counter() - t0

@@ -19,18 +19,23 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests use small ones, e.g. (2, 2)).
+
+    Axes are ``Auto``: the models state sharding intent with
+    ``with_sharding_constraint`` and leave the rest to the partitioner,
+    which ``jax.make_mesh``'s default ``Explicit`` axes do not allow.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh: Mesh) -> Tuple[str, ...]:

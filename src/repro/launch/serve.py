@@ -20,6 +20,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.core import OptimizerConfig, SLAConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serverless.platform import PlatformConfig
 from repro.serving.batcher import EngineBackedLatency
 from repro.serving.engine import EngineConfig, InferenceEngine
@@ -40,6 +41,7 @@ def main() -> None:
                    help="path to persist/restore proxy control-plane state")
     args = p.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_size:
         cfg = cfg.reduced()

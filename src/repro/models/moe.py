@@ -21,23 +21,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.distributed.constraint import ambient_mesh
 from repro.models.layers import GATED_ACTIVATIONS, activation_fn, dense_init
-
-# jax.shard_map landed after 0.4.x (older releases ship it under
-# jax.experimental.shard_map), and the check_rep→check_vma kwarg rename
-# happened in a separate release — so detect the kwarg on whichever
-# function exists rather than keying one off the other.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # pragma: no cover - exercised only on older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect as _inspect
-
-_SHARD_MAP_KW = (
-    {"check_vma": False}
-    if "check_vma" in _inspect.signature(_shard_map).parameters
-    else {"check_rep": False}
-)
 
 
 def init_moe(key, d_model: int, num_experts: int, expert_d_ff: int,
@@ -92,8 +77,6 @@ def moe_ffn(
       * **global** (no mesh — CPU tests, single device): sort-based
         dispatch into a capacity-bounded (E, C, D) buffer.
     """
-    from repro.distributed.constraint import ambient_mesh
-
     mesh = ambient_mesh()
     e = p["router"].shape[-1]
     if mesh is not None and "model" in mesh.axis_names:
@@ -257,9 +240,9 @@ def _moe_shard_map(p, x, *, mesh, dp_axes, top_k, capacity_factor,
 
     in_specs = (P(dp, None), P(None, None), wi_spec, wo_spec)
     out_specs = (P(dp, None), P())
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **_SHARD_MAP_KW)(xf, p["router"], p["wi"], p["wo"])
+        check_vma=False)(xf, p["router"], p["wi"], p["wo"])
     return y.reshape(b, s, d).astype(x.dtype), aux
 
 
@@ -346,30 +329,11 @@ def _moe_global(
     return y.reshape(b, s, d).astype(x.dtype), aux
 
 
-def _ambient_mesh():
-    """The mesh of the enclosing ``with mesh:`` / ``set_mesh`` scope, or None."""
-    import warnings
-
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and not am.empty:
-            return am
-    except Exception:
-        pass
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        try:
-            mesh = jax.interpreters.pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-    return None if mesh.empty else mesh
-
-
 def _shard(x: jax.Array, spec_axes: tuple) -> jax.Array:
     """Best-effort sharding constraint: apply only axes the ambient mesh has."""
     from jax.sharding import PartitionSpec as P
 
-    mesh = _ambient_mesh()
+    mesh = ambient_mesh()
     if mesh is None:
         return x
     axes = tuple(a if (a in mesh.axis_names) else None for a in spec_axes)

@@ -69,6 +69,9 @@ class ReplayResult:
     dispatch_log: list
     bucket_samples: Dict[int, List[float]]
     conservation: dict
+    #: one per submitted request, in arrival order; a served request's
+    #: ``ticket.request.payload`` holds what the target returned
+    tickets: List[RequestTicket]
 
 
 def _spawn_streams(
@@ -85,14 +88,16 @@ def run_replay(*, policy: str, sla: SLAConfig, arrivals: ArrivalProcess,
                policy_kwargs: Optional[dict] = None,
                config: Optional[RuntimeConfig] = None,
                clock: Optional[Clock] = None, seed: int = 0,
-               endpoint: str = "ep", pack: bool = False) -> ReplayResult:
+               endpoint: str = "ep", pack: bool = False,
+               payload_fn=None) -> ReplayResult:
     """Run one endpoint's workload through the live runtime, start to drain.
 
     Either pass a ready ``target`` or a ``workload`` latency model (wrapped
     in a :class:`SyntheticTarget` on the service RNG stream). ``clock``
     defaults to :class:`FakeClock` — deterministic and faster than real
     time; pass :class:`~repro.runtime.clock.WallClock` for a true
-    wall-clock run (the CI smoke does).
+    wall-clock run (the CI smoke does). ``payload_fn`` makes each
+    request's payload, as in :class:`LoadGenerator`.
     """
     clk = clock if clock is not None else FakeClock()
     arr_rng, svc_rng = _spawn_streams(seed)
@@ -105,7 +110,7 @@ def run_replay(*, policy: str, sla: SLAConfig, arrivals: ArrivalProcess,
     server.add_endpoint(endpoint, sla=sla, target=target, policy=policy,
                         policy_kwargs=policy_kwargs, pack=pack)
     gen = LoadGenerator(server, arrivals, duration=duration, rng=arr_rng,
-                        endpoint=endpoint)
+                        endpoint=endpoint, payload_fn=payload_fn)
 
     async def main() -> None:
         await server.start()
@@ -120,4 +125,5 @@ def run_replay(*, policy: str, sla: SLAConfig, arrivals: ArrivalProcess,
         bucket_samples={b: list(v)
                         for b, v in server.bucket_samples[endpoint].items()},
         conservation=server.conservation(),
+        tickets=gen.tickets,
     )

@@ -34,6 +34,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -90,16 +91,38 @@ class EngineConfig:
     cache_pool: bool = True
 
 
+class ReplicaUnavailable(RuntimeError):
+    """A replica cannot serve; :meth:`ReplicaPool.generate` fails over.
+
+    Only this error (and :meth:`ReplicaPool.fail`) takes a replica out of
+    rotation. Any other error, a device error (``jax.errors.JaxRuntimeError``)
+    included, propagates to the caller with its cause.
+    """
+
+
 class InferenceEngine:
-    """Single-replica engine: bucketed compile cache + prefill/decode loop."""
+    """Single-replica engine: bucketed compile cache + prefill/decode loop.
+
+    ``device`` pins the replica: its params, KV pool and inputs are put on
+    that device, so compiled programs run there. None = JAX's default
+    device.
+    """
 
     def __init__(self, cfg: ModelConfig, engine_cfg: EngineConfig,
-                 params: Optional[Any] = None, rng: Optional[jax.Array] = None):
+                 params: Optional[Any] = None, rng: Optional[jax.Array] = None,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
         self.ecfg = engine_cfg
         self.model = Model(cfg)
+        self.device = device
         if params is None:
-            params = self.model.init(rng if rng is not None else jax.random.PRNGKey(0))
+            rng = rng if rng is not None else jax.random.PRNGKey(0)
+            if device is not None:
+                rng = jax.device_put(rng, device)  # init runs on ``device``
+            # one compiled program instead of an eager op per weight
+            params = jax.jit(self.model.init)(rng)
+        if device is not None:
+            params = jax.device_put(params, device)
         self.params = params
         self._prefill_cache: Dict[Tuple[int, int], Any] = {}
         self._decode_cache: Dict[int, Any] = {}
@@ -175,6 +198,8 @@ class InferenceEngine:
         cache = self._kv_pool.pop(bucket, None) if self.ecfg.cache_pool else None
         if cache is None:
             cache = self.model.init_cache(bucket, self.ecfg.max_len)
+            if self.device is not None:
+                cache = jax.device_put(cache, self.device)
             self.cache_allocs += 1
         return cache
 
@@ -222,7 +247,51 @@ class InferenceEngine:
             self._in_warmup = False
         return timings
 
+    def _prefill(self, prompts: np.ndarray):
+        """Left-pad ``prompts`` into their buckets and run the compiled
+        prefill on a pooled cache → (last-position logits, cache, bucket,
+        prompt bucket)."""
+        n, plen_raw = prompts.shape
+        bucket = next_bucket(n, self.ecfg.batch_buckets)
+        plen = next_bucket(plen_raw, self.ecfg.prompt_buckets)
+        padded = np.zeros((bucket, plen), np.int32)
+        padded[:n, plen - plen_raw:] = prompts  # left-pad into the bucket
+        tokens = jax.device_put(padded, self.device)
+        cache = self._checkout_cache(bucket)
+        logits, cache = self._prefill_fn(bucket, plen)(self.params, tokens, cache)
+        return logits, cache, bucket, plen
+
     # ------------------------------------------------------------------ api
+    def programs(self, bucket: int, plen: int) -> Dict[str, Any]:
+        """The jitted programs :meth:`generate` runs for one batch bucket and
+        prompt bucket, by name, for lowering and inspection.
+
+        ``"prefill"`` takes (params, tokens (bucket, plen), cache); the
+        decode program, ``"fused decode"`` or (with ``fused_decode`` off)
+        ``"decode step"``, takes (params, tokens (bucket, 1), cache).
+        """
+        out = {"prefill": self._prefill_fn(bucket, plen)}
+        if self.ecfg.fused_decode:
+            steps = self._gen_steps(self.ecfg.gen_len, plen)
+            out["fused decode"] = self._fused_fn(bucket, steps)
+        else:
+            out["decode step"] = self._decode_fn(bucket)
+        return out
+
+    def device_arrays(self) -> List[jax.Array]:
+        """Every array the replica holds: its params and pooled KV caches."""
+        return jax.tree.leaves(self.params) + jax.tree.leaves(self._kv_pool)
+
+    def prefill_logits(self, prompts: np.ndarray) -> np.ndarray:
+        """Last-position logits (n, vocab) of the compiled prefill program.
+
+        The same program and padding :meth:`generate` uses, exposed so a
+        reference forward pass can be compared with what the engine serves.
+        """
+        logits, cache, bucket, _ = self._prefill(prompts)
+        self._return_cache(bucket, cache)
+        return np.asarray(jax.device_get(logits))[:prompts.shape[0], -1]
+
     def generate(self, prompts: np.ndarray, gen_len: Optional[int] = None,
                  ) -> Tuple[np.ndarray, Dict[str, float]]:
         """Greedy-decode ``gen_len`` tokens for a batch of prompts.
@@ -231,16 +300,9 @@ class InferenceEngine:
         (n, gen_len), timing dict with wall seconds + bucket metadata).
         """
         gen_len = gen_len if gen_len is not None else self.ecfg.gen_len
-        n, plen_raw = prompts.shape
-        bucket = next_bucket(n, self.ecfg.batch_buckets)
-        plen = next_bucket(plen_raw, self.ecfg.prompt_buckets)
+        n = prompts.shape[0]
         t0 = time.perf_counter()
-        padded = np.zeros((bucket, plen), np.int32)
-        padded[:n, plen - plen_raw:] = prompts  # left-pad into the bucket
-        tokens = jnp.asarray(padded)
-
-        cache = self._checkout_cache(bucket)
-        logits, cache = self._prefill_fn(bucket, plen)(self.params, tokens, cache)
+        logits, cache, bucket, plen = self._prefill(prompts)
         first = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
         if self.ecfg.fused_decode and gen_len > 1:
             steps = self._gen_steps(gen_len, plen)
@@ -276,6 +338,12 @@ class ReplicaPool:
     replica down (its in-flight work is retried elsewhere); ``scale_to``
     adds/removes replicas.
 
+    ``devices`` spreads replicas over accelerators, one per device: replica
+    ``i`` holds its KV pool and a copy of the weights on ``devices[i]``
+    (replica 0 shares the template's, which are made on ``devices[0]``).
+    None keeps every replica on the default device, sharing the template's
+    weights.
+
     Dispatch is **parallel across replicas**: each replica is guarded by
     its own lock (a replica's compile caches and KV pool are not
     thread-safe), and ``generate`` prefers an *idle* healthy replica over
@@ -287,9 +355,13 @@ class ReplicaPool:
     """
 
     def __init__(self, cfg: ModelConfig, engine_cfg: EngineConfig,
-                 n_replicas: int = 1, rng: Optional[jax.Array] = None):
+                 n_replicas: int = 1, rng: Optional[jax.Array] = None,
+                 devices: Optional[Sequence[jax.Device]] = None):
         rng = rng if rng is not None else jax.random.PRNGKey(0)
-        self._template = InferenceEngine(cfg, engine_cfg, rng=rng)
+        self.devices = list(devices) if devices else None
+        self._template = InferenceEngine(
+            cfg, engine_cfg, rng=rng,
+            device=self.devices[0] if self.devices else None)
         self.cfg = cfg
         self.engine_cfg = engine_cfg
         self.replicas: List[Optional[InferenceEngine]] = []
@@ -309,14 +381,17 @@ class ReplicaPool:
         """
         if n < 0:
             raise ValueError(f"replica count must be >= 0, got {n}")
+        if self.devices and n > len(self.devices):
+            raise ValueError(f"{n} replicas for {len(self.devices)} devices")
         if n < len(self.replicas):
             del self.replicas[n:]
             del self.healthy[n:]
             del self._locks[n:]
             self._rr = self._rr % len(self.replicas) if self.replicas else 0
         while len(self.replicas) < n:
+            device = self.devices[len(self.replicas)] if self.devices else None
             eng = InferenceEngine(self.cfg, self.engine_cfg,
-                                  params=self._template.params)
+                                  params=self._template.params, device=device)
             self.replicas.append(eng)
             self.healthy.append(True)
             self._locks.append(threading.Lock())
@@ -332,12 +407,19 @@ class ReplicaPool:
         self.healthy[index] = True
 
     def warmup(self, plen: Optional[int] = None) -> Dict[Tuple[int, int], float]:
-        """Warm every replica; returns the first replica's timings."""
-        timings: Dict[Tuple[int, int], float] = {}
-        for i, eng in enumerate(self.replicas):
-            t = eng.warmup(plen)
-            if i == 0:
-                timings = t
+        """Warm every replica; returns the first replica's timings.
+
+        The first replica warms alone, so its timings are not skewed by
+        others sharing its device. The rest then warm in parallel threads:
+        each compiles its own programs, and XLA compiles without the GIL.
+        """
+        if not self.replicas:
+            return {}
+        timings = self.replicas[0].warmup(plen)
+        rest = self.replicas[1:]
+        if rest:
+            with ThreadPoolExecutor(max_workers=len(rest)) as ex:
+                list(ex.map(lambda eng: eng.warmup(plen), rest))
         return timings
 
     def _acquire_replica(self) -> Tuple[Optional[int], Optional[threading.Lock]]:
@@ -367,11 +449,14 @@ class ReplicaPool:
         return None, None
 
     def generate(self, prompts: np.ndarray, gen_len: Optional[int] = None):
-        """Idle-preferring round-robin dispatch with failover (at-least-once)."""
-        if not self.replicas:
-            raise RuntimeError("no healthy replicas")
+        """Idle-preferring round-robin dispatch with failover (at-least-once).
+
+        A replica that raises :class:`ReplicaUnavailable` is marked down and
+        the batch retried on another; every other error propagates.
+        """
+        last: Optional[ReplicaUnavailable] = None
         attempts = 0
-        while attempts <= len(self.replicas):
+        while self.replicas and attempts <= len(self.replicas):
             idx, lock = self._acquire_replica()
             if idx is None:
                 attempts += 1
@@ -380,10 +465,11 @@ class ReplicaPool:
                 out, timing = self.replicas[idx].generate(prompts, gen_len)
                 timing["replica"] = idx
                 return out, timing
-            except RuntimeError:
+            except ReplicaUnavailable as e:
+                last = e
                 self.fail(idx)
                 self.retries += 1
                 attempts += 1
             finally:
                 lock.release()
-        raise RuntimeError("no healthy replicas")
+        raise ReplicaUnavailable("no healthy replicas") from last

@@ -12,12 +12,12 @@ import pytest
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def run_py(code: str) -> str:
+def run_py(code: str, timeout: float = 560) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=560)
+                         text=True, env=env, timeout=timeout)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 
@@ -47,7 +47,8 @@ import jax
 from repro.configs import get_config
 from repro.distributed.sharding import param_spec
 from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 cfg = get_config("yi-34b")
 assert param_spec("layers/attn/wq", (60, 7168, 7168), mesh, cfg) == P(None, "data", "model")
 assert param_spec("layers/attn/wo", (60, 7168, 7168), mesh, cfg) == P(None, "model", "data")
@@ -74,7 +75,8 @@ from repro.distributed import sharding as shd
 from repro.optim import adamw
 cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(), num_layers=2)
 model = Model(cfg)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 params = model.init(jax.random.PRNGKey(0))
 psh = shd.shard_params(params, mesh, cfg)
 params = jax.device_put(params, psh)
@@ -85,7 +87,7 @@ def step(p, o, batch):
     loss, g = jax.value_and_grad(model.loss)(p, batch)
     p, o, m = adamw.apply_updates(opt_cfg, p, g, o)
     return p, o, loss
-with mesh:
+with jax.set_mesh(mesh):
     p2, o2, loss = jax.jit(step)(params, opt, {"tokens": tokens, "labels": tokens})
 assert jnp.isfinite(loss), loss
 print("loss", float(loss))
@@ -103,11 +105,12 @@ from repro.models.moe import init_moe, moe_ffn, _moe_global
 key = jax.random.PRNGKey(0)
 D,E,F = 32, 8, 64
 p = init_moe(key, D, E, F, "silu", jnp.float32)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 for (B, S, tag) in [(4, 8, "token-route"), (8, 32, "weight-gather")]:
     x = jax.random.normal(jax.random.fold_in(key, B), (B, S, D))
     y_ref, _ = _moe_global(p, x, top_k=2, capacity_factor=8.0)
-    with mesh:
+    with jax.set_mesh(mesh):
         y_sm, _ = jax.jit(lambda p, x: moe_ffn(p, x, top_k=2, capacity_factor=8.0))(p, x)
     err = float(jnp.max(jnp.abs(y_ref - y_sm)))
     assert err < 1e-5, (tag, err)
@@ -115,6 +118,40 @@ for (B, S, tag) in [(4, 8, "token-route"), (8, 32, "weight-gather")]:
 """
     out = run_py(code)
     assert out.count("OK") == 2
+
+
+def test_replica_pool_one_replica_per_device():
+    """Four replicas on four devices: each replica's params and KV pool
+    live on its own device, and every replica serves the same tokens as a
+    one-replica pool."""
+    code = """
+import jax, numpy as np
+from repro.configs import get_config
+from repro.serving.engine import EngineConfig, ReplicaPool
+cfg = get_config("qwen2-0.5b").reduced()
+ecfg = EngineConfig(batch_buckets=(2,), prompt_buckets=(8,), max_len=16, gen_len=3)
+devs = jax.devices()
+prompts = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 8)).astype(np.int32)
+want, _ = ReplicaPool(cfg, ecfg, n_replicas=1).generate(prompts)
+pool = ReplicaPool(cfg, ecfg, n_replicas=4, devices=devs)
+served = set()
+for _ in range(4):
+    out, timing = pool.generate(prompts)
+    np.testing.assert_array_equal(out, want)
+    served.add(timing["replica"])
+assert served == {0, 1, 2, 3}, served
+for i, eng in enumerate(pool.replicas):
+    leaves = eng.device_arrays()
+    assert leaves and all(x.devices() == {devs[i]} for x in leaves), i
+try:
+    pool.scale_to(5)
+except ValueError:
+    pass
+else:
+    raise AssertionError("a fifth replica was given a device")
+print("PLACED-OK")
+"""
+    assert "PLACED-OK" in run_py(code, timeout=120)
 
 
 def test_multipod_mesh_axes():

@@ -104,11 +104,12 @@ model = Model(cfg)
 params = model.init(jax.random.PRNGKey(0))
 d = {str(tmp_path)!r}
 ckpt.save_checkpoint(d, 7, params, metadata={{"arch": cfg.name}})
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 step, restored, meta = restore_elastic(d, params, mesh, cfg)
 assert step == 7 and meta["arch"] == cfg.name
 tok = jnp.zeros((2, 8), jnp.int32)
-with mesh:
+with jax.set_mesh(mesh):
     logits = jax.jit(model.forward)(restored, tok)
 ref = model.forward(params, tok)
 import numpy as np

@@ -177,3 +177,36 @@ def test_engine_backed_latency_seed_prefers_nearest_prompt_bucket():
     lat._ema = {}
     lat.seed({(1, 4): 0.5, (1, 8): 0.1, (2, 8): 0.2})
     assert lat._ema == {1: 0.1, 2: 0.2}
+
+
+# --------------------------------------------------------- public accessors
+@pytest.mark.parametrize("fused", [True, False])
+def test_programs_are_the_ones_generate_runs(shared_params, fused):
+    """``programs`` hands out the cached programs ``generate`` compiled."""
+    eng = InferenceEngine(TINY, _ecfg(fused_decode=fused), params=shared_params)
+    eng.generate(np.ones((2, 4), np.int32))
+    before = eng.compile_count
+    progs = eng.programs(2, 4)
+    assert eng.compile_count == before
+    decode = "fused decode" if fused else "decode step"
+    assert list(progs) == ["prefill", decode]
+    assert progs["prefill"] is eng._prefill_fn(2, 4)
+
+
+def test_prefill_logits_match_model_forward(shared_params):
+    eng = InferenceEngine(TINY, _ecfg(), params=shared_params)
+    x = np.random.default_rng(5).integers(
+        0, TINY.vocab_size, (2, 8)).astype(np.int32)
+    got = eng.prefill_logits(x)
+    want = np.asarray(eng.model.forward(shared_params, jax.numpy.asarray(x)))
+    np.testing.assert_allclose(got, want[:, -1], rtol=1e-5, atol=1e-5)
+
+
+def test_device_arrays_hold_params_and_pooled_caches(shared_params):
+    eng = InferenceEngine(TINY, _ecfg(), params=shared_params)
+    n_params = len(jax.tree.leaves(shared_params))
+    assert len(eng.device_arrays()) == n_params
+    eng.generate(np.ones((2, 4), np.int32))
+    eng.generate(np.ones((4, 4), np.int32))
+    n_cache = len(jax.tree.leaves(eng.model.init_cache(1, 8)))
+    assert len(eng.device_arrays()) == n_params + 2 * n_cache

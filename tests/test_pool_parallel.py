@@ -24,7 +24,7 @@ class _BlockingStubEngine:
     _mu = threading.Lock()
     release = threading.Event()
 
-    def __init__(self, cfg, engine_cfg, params=None, rng=None):
+    def __init__(self, cfg, engine_cfg, params=None, rng=None, device=None):
         self.cfg = cfg
         self.engine_cfg = engine_cfg
         self.params = params if params is not None else object()
@@ -95,7 +95,7 @@ def test_same_replica_calls_serialize(blocking_pool):
 
 
 class _CountingStubEngine:
-    def __init__(self, cfg, engine_cfg, params=None, rng=None):
+    def __init__(self, cfg, engine_cfg, params=None, rng=None, device=None):
         self.cfg = cfg
         self.engine_cfg = engine_cfg
         self.params = params if params is not None else object()
@@ -104,7 +104,7 @@ class _CountingStubEngine:
 
     def generate(self, prompts, gen_len=None):
         if self.fail:
-            raise RuntimeError("injected replica failure")
+            raise engine_mod.ReplicaUnavailable("injected replica failure")
         self.calls += 1
         return prompts[:, :1], {"latency_s": 0.001, "bucket": len(prompts)}
 
@@ -115,8 +115,9 @@ def test_failed_replica_lock_is_released(monkeypatch):
                        rng=np.zeros(2))
     pool.replicas[0].fail = True
     pool.replicas[1].fail = True
-    with pytest.raises(RuntimeError, match="no healthy replicas"):
+    with pytest.raises(RuntimeError, match="no healthy replicas") as err:
         pool.generate(np.zeros((1, 4), np.int32))
+    assert "injected replica failure" in str(err.value.__cause__)
     # the failover path must not leak a held lock on the failed replicas
     assert all(not lk.locked() for lk in pool._locks)
     pool.recover(0)

@@ -15,7 +15,7 @@ from repro.serving.engine import ReplicaPool
 class _StubEngine:
     """Stands in for InferenceEngine: records calls, no JAX."""
 
-    def __init__(self, cfg, engine_cfg, params=None, rng=None):
+    def __init__(self, cfg, engine_cfg, params=None, rng=None, device=None):
         self.cfg = cfg
         self.engine_cfg = engine_cfg
         self.params = params if params is not None else object()
@@ -24,7 +24,7 @@ class _StubEngine:
 
     def generate(self, prompts, gen_len=None):
         if self.fail:
-            raise RuntimeError("injected replica failure")
+            raise engine_mod.ReplicaUnavailable("injected replica failure")
         self.calls += 1
         return prompts[:, :1], {"latency_s": 0.001, "bucket": len(prompts)}
 
@@ -105,3 +105,21 @@ def test_failover_skips_failed_replica(pool):
     assert 1 not in seen
     assert pool.n_healthy == 3
     assert pool.retries >= 1
+
+
+def test_device_error_propagates_without_failover(pool):
+    """A device error (OOM, failed compile) reaches the caller with its
+    message: it neither marks replicas down nor turns into the pool's own
+    "no healthy replicas"."""
+    import jax
+
+    def device_error(prompts, gen_len=None):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    for eng in pool.replicas:
+        eng.generate = device_error
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="RESOURCE_EXHAUSTED") as err:
+        pool.generate(np.zeros((1, 4), np.int32))
+    assert "no healthy replicas" not in str(err.value)
+    assert pool.n_healthy == 4 and pool.retries == 0
