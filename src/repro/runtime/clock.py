@@ -90,7 +90,8 @@ class FakeClock(Clock):
 
     # Safety bound on settle iterations: a genuine ping-pong livelock
     # (two tasks re-scheduling each other forever without blocking)
-    # should fail loudly rather than hang the test suite.
+    # should fail loudly rather than hang the test suite. A coroutine that
+    # spins without ever suspending never lets this loop run at all.
     MAX_SETTLE = 100_000
 
     def __init__(self, start: float = 0.0) -> None:

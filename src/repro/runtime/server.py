@@ -510,9 +510,7 @@ class AsyncProxyServer:
         self._accepting = False
         self.frontend.flush(self.clock.now())
         if timeout is None:
-            while self._batch_tasks:
-                await asyncio.gather(*list(self._batch_tasks),
-                                     return_exceptions=True)
+            await self._await_batches()
         else:
             await self._drain_bounded(timeout)
         self._running = False
@@ -527,15 +525,8 @@ class AsyncProxyServer:
         # Let freshly created batch tasks take their first step so each
         # one owns its bookkeeping before any cancellation can reach it.
         await asyncio.sleep(0)
-        loop = asyncio.get_running_loop()
-
-        async def settle() -> None:
-            while self._batch_tasks:
-                await asyncio.gather(*list(self._batch_tasks),
-                                     return_exceptions=True)
-
-        waiter = loop.create_task(settle())
-        timer = loop.create_task(self.clock.sleep(timeout))
+        waiter = asyncio.create_task(self._await_batches())
+        timer = asyncio.create_task(self.clock.sleep(timeout))
         await asyncio.wait({waiter, timer},
                            return_when=asyncio.FIRST_COMPLETED)
         if waiter.done():
@@ -552,6 +543,13 @@ class AsyncProxyServer:
         # _run_batch converts the cancellation into failed-accounting and
         # finishes normally; gather collects stragglers either way.
         await asyncio.gather(*stragglers, return_exceptions=True)
+
+    async def _await_batches(self) -> None:
+        """Await in-flight batches until none is left. A done task leaves
+        ``_batch_tasks`` by a callback on the loop's next turn, so each
+        pass must suspend: ``gather`` of done tasks does not (3.12+)."""
+        while self._batch_tasks:
+            await asyncio.wait(list(self._batch_tasks))
 
     # -------------------------------------------------------------- ingress
     def submit(self, request: Optional[Request] = None, *,

@@ -28,7 +28,7 @@ def _run(env_dir):
     if env_dir is not None:
         env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
     out = subprocess.run([sys.executable, "-c", CODE], capture_output=True,
-                         text=True, env=env, timeout=120)
+                         text=True, env=env, timeout=90)
     assert out.returncode == 0, out.stderr[-2000:]
     return out.stdout.strip().splitlines()[-1]
 

@@ -12,12 +12,12 @@ import pytest
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def run_py(code: str, timeout: float = 560) -> str:
+def run_py(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=timeout)
+                         text=True, env=env, timeout=90)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
 
@@ -151,7 +151,7 @@ else:
     raise AssertionError("a fifth replica was given a device")
 print("PLACED-OK")
 """
-    assert "PLACED-OK" in run_py(code, timeout=120)
+    assert "PLACED-OK" in run_py(code)
 
 
 def test_multipod_mesh_axes():
@@ -167,7 +167,7 @@ print("OK-single")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=256"
     env["PYTHONPATH"] = SRC
     out = subprocess.run([sys.executable, "-c", env_code], capture_output=True,
-                         text=True, env=env, timeout=120)
+                         text=True, env=env, timeout=90)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "OK-single" in out.stdout
 

@@ -120,7 +120,7 @@ print("ELASTIC-OK")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=560)
+                         text=True, env=env, timeout=90)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "ELASTIC-OK" in out.stdout
 

@@ -9,15 +9,17 @@ round-trip (measure → fit → simulate within 10%).
 """
 import asyncio
 import math
+import time
 
 import numpy as np
 import pytest
 
 from repro.core import SLAConfig, ms
 from repro.core.config import OptimizerConfig, ProxyConfig
-from repro.runtime import (AsyncProxyServer, Calibration, FakeClock,
-                           LoadGenerator, RuntimeConfig, SyntheticTarget,
-                           WallClock, clamp_policy_kwargs, run, run_replay)
+from repro.runtime import (AsyncProxyServer, Calibration, DispatchTarget,
+                           FakeClock, LoadGenerator, RuntimeConfig,
+                           SyntheticTarget, WallClock, clamp_policy_kwargs,
+                           run, run_replay)
 from repro.serverless.latency import AffineLatency, MeasuredLatency, get_workload
 from repro.serverless.platform import PlatformConfig
 from repro.simulation.arrivals import (MMPP2, PoissonProcess, Schedule,
@@ -215,6 +217,37 @@ class TestDrain:
         assert all(t.future.done() and not t.rejected for t in tickets)
         assert server.completed == 7
         assert [e[4] for e in server.dispatch_log] == ["flush"]
+
+    @pytest.mark.parametrize("timeout", [None, 1.0],
+                             ids=["drain()", "drain(timeout=1.0)"])
+    def test_drain_right_after_last_batch_returns(self, timeout):
+        """Drain entered in the loop turn that finished the last batch.
+
+        The finished task is still in the server's task set until the
+        loop runs its done-callback; drain must give the loop that turn
+        (an eager ``gather`` of done tasks spun here on Python 3.12+).
+        """
+        clock = WallClock()
+        server = AsyncProxyServer(clock=clock)
+
+        class Instant(DispatchTarget):
+            async def __call__(self, batch, deadline=None):
+                return None
+
+        server.add_endpoint("ep", sla=SLA, target=Instant(),
+                            policy="passthrough")
+
+        async def main():
+            await server.start()
+            ticket = server.submit(endpoint="ep")
+            await ticket.future
+            start = time.monotonic()
+            await server.drain(timeout=timeout)
+            return time.monotonic() - start
+
+        assert run(clock, main()) < 1.0
+        c = server.assert_conserved(require_drained=True)
+        assert c["completed"] == 1 and c["failed"] == 0
 
 
 # --------------------------------------------------------------- targets
