@@ -238,7 +238,8 @@ class SmartMonitor:
 
     Responsibilities (paper §2.2):
       * per-batch-size sliding windows of upstream response times →
-        ``upstream_percentile(bs)`` (the scheduler's ``RT95[N_q+1]``);
+        ``upstream_percentile(bs)`` (the scheduler's ``RT95[N_q+1]``, at its
+        bucket on a bucketed endpoint);
       * sliding window of end-to-end response times → ``e2e_percentile()``;
       * dispatch-cause accounting over the current optimizer interval →
         ``timeout_ratio()``.
@@ -275,6 +276,10 @@ class SmartMonitor:
         # into a bucket of size b occupies b slots, b - n of them padding
         self._c_dispatched_slots = c("lifetime_dispatched_slots")
         self._c_padded_slots = c("lifetime_padded_slots")
+        # upstream_percentile answers not read from a window of the asked
+        # size (the regression or the optimistic default): dispatch
+        # decisions priced from no measured batch of that size
+        self._c_estimate_fallbacks = c("lifetime_estimate_fallbacks")
         # SLO burn-rate meter fed by every end-to-end completion: the
         # windowed violation rate over the SLA's error budget, on a fast
         # and a slow window (SRE-style multi-window burn alerting).
@@ -319,6 +324,10 @@ class SmartMonitor:
     @property
     def lifetime_padded_slots(self) -> int:
         return self._c_padded_slots.value
+
+    @property
+    def lifetime_estimate_fallbacks(self) -> int:
+        return self._c_estimate_fallbacks.value
 
     def register_metrics(self, registry: MetricsRegistry,
                          prefix: str = "monitor") -> None:
@@ -427,6 +436,7 @@ class SmartMonitor:
                                    outlier_mult=cfg.outlier_mult)
                 if v is not None:
                     return v
+        self._c_estimate_fallbacks.inc()
         return self._regression_estimate(batch_size, now)
 
     def _regression_estimate(self, batch_size: int, now: float) -> float:

@@ -4,8 +4,13 @@ Event-driven implementation of the paper's queue scheduler: on every arrival
 the dispatch timeout is recomputed from the monitor's latency estimate for a
 batch one larger than the current queue,
 
-    DTO = SLO_T − RT_p95[N_q + 1]
+    DTO = SLO_T − RT_p95[bucket(N_q + 1)]
     TO  = DTO − FRT        (FRT = age of the oldest queued request)
+
+where ``bucket`` is the identity on an unbucketed endpoint (the paper's
+``RT_p95[N_q + 1]``) and, on a bucketed one, the padded bucket the engine
+would run a batch of ``N_q + 1`` as — the size the monitor keys its
+windows by, and the cost the batch will actually pay.
 
 and the batch is dispatched when (a) it reaches ``Max_BS``, (b) the timeout
 fires, or (c) ``TO ≤ 0`` at recomputation time (the paper's "negative DTO →
@@ -81,8 +86,7 @@ class QueueScheduler:
         else:
             # Bucket-aware packing: round Max_BS up to the next engine
             # bucket edge and dispatch exactly at it — a "full" batch then
-            # executes with zero padding, and the monitor's RT95[bucket]
-            # keying means the timeout math already prices the edge.
+            # executes with zero padding.
             target = bucket_of(max_bs, pack)
             while self.queue.queue_len >= target:
                 if self.queue._dispatch(now, cause="full",
@@ -92,8 +96,12 @@ class QueueScheduler:
                 return
 
         # DTO = SLO − RT95[N_q + 1]; probing one size larger guards against
-        # the latency of the batch after one more arrival (paper eq. 1).
-        est = self.monitor.upstream_percentile(self.queue.queue_len + 1, now)
+        # the latency of the batch after one more arrival (paper eq. 1). On
+        # a bucketed endpoint N_q + 1 is priced at the bucket the engine
+        # will run it as, the padded size the monitor keys its windows by;
+        # a raw size between edges would be priced by a line between them.
+        probe = bucket_of(self.queue.queue_len + 1, self.config.bucketing)
+        est = self.monitor.upstream_percentile(probe, now)
         dto = self.config.sla.slo_target - est - self.config.dispatch_overhead
         to = dto - self.queue.frt(now)
         if to <= 0:

@@ -207,3 +207,28 @@ def test_latency_window_count_evicts_like_values():
     w.add(20.0, 3.0)
     assert w.count(21.0) == 1
     assert len(w) == 1
+
+
+def test_monitor_counts_estimate_fallbacks():
+    mon = SmartMonitor(MonitorConfig(min_samples=3), SLA)
+    # before any sample: the optimistic default is a fallback
+    mon.upstream_percentile(8, now=0.0)
+    assert mon.lifetime_estimate_fallbacks == 1
+    for bs in (8, 16):
+        for _ in range(3):
+            mon.record_upstream(bs, 0.01 * bs, now=0.0)
+    # a size with a window of its own: answered from it, not counted
+    assert mon.upstream_percentile(16, now=1.0) == pytest.approx(0.16)
+    assert mon.lifetime_estimate_fallbacks == 1
+    # a size with no window: the regression answers, and it counts
+    assert mon.upstream_percentile(12, now=1.0) == pytest.approx(0.12)
+    assert mon.lifetime_estimate_fallbacks == 2
+    # a window below min_samples is no window either
+    mon.record_upstream(4, 0.04, now=1.0)
+    mon.upstream_percentile(4, now=1.0)
+    assert mon.lifetime_estimate_fallbacks == 3
+    # bound into an aggregator's registry under the endpoint's prefix
+    from repro.obs.metrics import MetricsRegistry
+    reg = MetricsRegistry()
+    mon.register_metrics(reg, prefix="endpoint.ep")
+    assert reg.value("endpoint.ep.lifetime_estimate_fallbacks") == 3
