@@ -20,6 +20,7 @@ from typing import Dict, List, Sequence
 
 import numpy as np
 
+import arch
 import reference
 
 
@@ -40,8 +41,9 @@ def sample(groups: Sequence, n_min: int, seed: int) -> np.ndarray:
 
 
 def rows_per_block(cfg: dict, seq: int, budget_bytes: float = 1.5e9) -> int:
-    """Rows whose attention scores and widest activation fit ``budget``."""
-    wide = max(cfg["num_attention_heads"] * seq, 2 * cfg["intermediate_size"])
+    """Rows whose widest float32 activation over ``seq`` positions, as the
+    architecture's module counts it, fits ``budget``."""
+    wide = arch.load(cfg).widest(cfg, seq)
     return max(1, int(budget_bytes // (4 * seq * wide)))
 
 

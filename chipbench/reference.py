@@ -1,25 +1,23 @@
-"""Plain float32 reference of the dense decoder the benchmark serves.
+"""Plain float32 reference of the decoders the benchmark serves.
 
 It imports nothing of the program and takes nothing the program made: it
 builds the weights itself from the same seed, by the recipe below, and
 runs the published architecture in ``jax.numpy`` at float32 under
 ``default_matmul_precision("highest")``, with no cache, batching or
-kernels. Two variants, chosen by the configuration's ``model_type``:
-
-* ``qwen2``: RMSNorm, SwiGLU, biases on q/k/v only, tied output head.
-* ``starcoder2``: LayerNorm with bias, tanh GELU (``gelu_pytorch_tanh``),
-  a bias on every projection, untied output head.
-
-Both use rotary embeddings over the first and second half of each head
-(``rotate_half``), grouped-query attention and a causal mask.
+kernels. The architecture is the module ``arch/<model_type>.py`` (see
+``arch/__init__.py``): one layer's weights and forward, the final norm,
+and whether the head is tied. This file holds what every architecture
+shares: the draws of the weight recipe, the float8 rounding, both norms,
+rotary embeddings, causal grouped-query attention, SwiGLU, and the pass
+over embedding, layers and head.
 
 The weight recipe, which the program's ``Model.init`` follows too: from
 ``PRNGKey(s)``, split (embedding, layers, head); split the layers' key
-into one per layer; each layer splits (attention, mlp, unused), attention
-splits (q, k, v, o) and the mlp (in, out). A projection of fan-in ``n``
-is ``normal / sqrt(n)``, the embedding ``normal * 0.02``, the untied head
-``normal / sqrt(hidden)``; every value is rounded to the stored type
-(bfloat16). Norm scales are one; every bias is zero.
+into one per layer, from which the architecture's module draws that
+layer. A projection of fan-in ``n`` is ``normal / sqrt(n)``, the
+embedding ``normal * 0.02``, the untied head ``normal / sqrt(hidden)``;
+every value is rounded to the stored type (bfloat16). Norm scales are
+one; every bias is zero.
 
 The weights are made layer by layer, and the reference runs layer by
 layer over all rows, so that it fits in a chip's memory beside nothing
@@ -33,11 +31,13 @@ from __future__ import annotations
 import functools
 import json
 import math
-from typing import Dict, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import arch
 
 F8 = jnp.float8_e4m3fn
 F8_MAX = float(jnp.finfo(F8).max)
@@ -45,29 +45,9 @@ STORED = {"bfloat16": jnp.bfloat16, "float16": jnp.float16,
           "float32": jnp.float32}
 
 
-def _arch(cfg: dict) -> dict:
-    kind = cfg["model_type"]
-    if kind == "qwen2":
-        return {"layer_norm": False, "eps": cfg["rms_norm_eps"], "gated": True,
-                "qkv_bias": True, "proj_bias": False,
-                "tied": cfg["tie_word_embeddings"]}
-    if kind == "starcoder2":
-        return {"layer_norm": True, "eps": cfg["norm_epsilon"], "gated": False,
-                "qkv_bias": cfg["use_bias"], "proj_bias": cfg["use_bias"],
-                "tied": cfg["tie_word_embeddings"]}
-    raise ValueError(f"no reference for model_type {kind!r}")
-
-
-def _dims(cfg: dict):
-    d = cfg["hidden_size"]
-    hq = cfg["num_attention_heads"]
-    hkv = cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim") or d // hq
-    return d, hq, hkv, hd
-
-
 # ------------------------------------------------------------------ weights
-def _normal(key, shape, scale, stored):
+def normal(key, shape, scale, stored):
+    """``normal * scale`` rounded to the stored type, held in float32."""
     w = jax.random.normal(key, shape) * scale
     return w.astype(stored).astype(jnp.float32)
 
@@ -77,69 +57,39 @@ def _keys(cfg: dict, key_seed: int):
     return k_emb, jax.random.split(k_layers, cfg["num_hidden_layers"]), k_head
 
 
-def _layer_weights(cfg: dict, key) -> Dict[str, jax.Array]:
-    d, hq, hkv, hd = _dims(cfg)
-    f = cfg["intermediate_size"]
-    a = _arch(cfg)
-    stored = STORED[cfg["torch_dtype"]]
-    ka, km, _ = jax.random.split(key, 3)
-    kq, kk, kv, ko = jax.random.split(ka, 4)
-    k1, k2 = jax.random.split(km)
-    wi_out = 2 * f if a["gated"] else f
-    w = {
-        "wq": _normal(kq, (d, hq * hd), 1.0 / math.sqrt(d), stored),
-        "wk": _normal(kk, (d, hkv * hd), 1.0 / math.sqrt(d), stored),
-        "wv": _normal(kv, (d, hkv * hd), 1.0 / math.sqrt(d), stored),
-        "wo": _normal(ko, (hq * hd, d), 1.0 / math.sqrt(hq * hd), stored),
-        "wi": _normal(k1, (d, wi_out), 1.0 / math.sqrt(d), stored),
-        "wd": _normal(k2, (f, d), 1.0 / math.sqrt(f), stored),
-        "bq": jnp.zeros(hq * hd), "bk": jnp.zeros(hkv * hd),
-        "bv": jnp.zeros(hkv * hd), "bo": jnp.zeros(d),
-        "bi": jnp.zeros(wi_out), "bd": jnp.zeros(d),
-        "n1": jnp.ones(d), "n1b": jnp.zeros(d),
-        "n2": jnp.ones(d), "n2b": jnp.zeros(d),
-    }
-    if not a["qkv_bias"]:
-        for k in ("bq", "bk", "bv"):
-            del w[k]
-    if not a["proj_bias"]:
-        for k in ("bo", "bi", "bd"):
-            del w[k]
-    return w
-
-
 # ------------------------------------------------------------------ compute
-def _f8(x, axis):
+def f8(x, axis):
     """Round ``x`` to float8 e4m3 with one scale per slice along ``axis``."""
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
     scale = jnp.where(amax > 0, F8_MAX / amax, 1.0)
     return (x * scale).astype(F8).astype(jnp.float32) / scale
 
 
-def _matmul(x, w, control: bool):
+def matmul(x, w, control: bool):
+    """``x @ w``; with ``control``, of float8-rounded operands."""
     if control:
-        x, w = _f8(x, -1), _f8(w, None)
+        x, w = f8(x, -1), f8(w, None)
     return x @ w
 
 
-def _norm(a: dict, x, scale, bias):
-    if a["layer_norm"]:
-        mu = jnp.mean(x, -1, keepdims=True)
-        var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
-        return (x - mu) * jax.lax.rsqrt(var + a["eps"]) * scale + bias
+def rms_norm(x, scale, eps: float):
     var = jnp.mean(jnp.square(x), -1, keepdims=True)
-    return x * jax.lax.rsqrt(var + a["eps"]) * scale
+    return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _act(a: dict, h):
-    if a["gated"]:
-        gate, up = jnp.split(h, 2, axis=-1)
-        return gate * jax.nn.sigmoid(gate) * up
-    c = math.sqrt(2.0 / math.pi)
-    return 0.5 * h * (1.0 + jnp.tanh(c * (h + 0.044715 * h ** 3)))
+def layer_norm(x, scale, bias, eps: float):
+    mu = jnp.mean(x, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mu), -1, keepdims=True)
+    return (x - mu) * jax.lax.rsqrt(var + eps) * scale + bias
 
 
-def _rope(x, theta: float):
+def swiglu(h):
+    """SiLU of the first half times the second."""
+    gate, up = jnp.split(h, 2, axis=-1)
+    return gate * jax.nn.sigmoid(gate) * up
+
+
+def rope(x, theta: float):
     """x: (rows, seq, heads, hd); positions 0..seq-1, halves rotated."""
     hd = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
@@ -149,44 +99,46 @@ def _rope(x, theta: float):
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
 
 
-def _layer(cfg: dict, w, x, control: bool):
-    """One decoder layer over x: (rows, seq, hidden)."""
-    d, hq, hkv, hd = _dims(cfg)
-    a = _arch(cfg)
-    r, s, _ = x.shape
-    h = _norm(a, x, w["n1"], w["n1b"])
-    q = _matmul(h, w["wq"], control) + w.get("bq", 0.0)
-    k = _matmul(h, w["wk"], control) + w.get("bk", 0.0)
-    v = _matmul(h, w["wv"], control) + w.get("bv", 0.0)
-    q = _rope(q.reshape(r, s, hq, hd), cfg["rope_theta"])
-    k = _rope(k.reshape(r, s, hkv, hd), cfg["rope_theta"])
-    v = v.reshape(r, s, hkv, hd)
-    g = hq // hkv
-    q = q.reshape(r, s, hkv, g, hd)
+def causal_attention(q, k, v):
+    """Causal grouped-query attention. q: (rows, seq, hq, hd); k, v:
+    (rows, seq, hkv, hd) → (rows, seq, hq * hd)."""
+    r, s, hq, hd = q.shape
+    hkv = k.shape[2]
+    q = q.reshape(r, s, hkv, hq // hkv, hd)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) / math.sqrt(hd)
     causal = jnp.tril(jnp.ones((s, s), bool))
     scores = jnp.where(causal, scores, -jnp.inf)
     att = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(scores, -1), v)
-    att = att.reshape(r, s, hq * hd)
-    x = x + _matmul(att, w["wo"], control) + w.get("bo", 0.0)
-    h = _norm(a, x, w["n2"], w["n2b"])
-    h = _act(a, _matmul(h, w["wi"], control) + w.get("bi", 0.0))
-    return x + _matmul(h, w["wd"], control) + w.get("bd", 0.0)
+    return att.reshape(r, s, hq * hd)
+
+
+def _kinds(mod, cfg: dict):
+    kind = getattr(mod, "layer_kind", None)
+    return [kind(cfg, i) if kind else None
+            for i in range(cfg["num_hidden_layers"])]
 
 
 @functools.lru_cache(maxsize=None)
 def _programs(cfg_key: str, control: bool):
-    """The jitted pieces of a forward pass, made once per configuration."""
+    """The jitted pieces of a forward pass, made once per configuration
+    (the layer's two per kind of layer)."""
     cfg = json.loads(cfg_key)
+    mod = arch.load(cfg)
     d, v = cfg["hidden_size"], cfg["vocab_size"]
     stored = STORED[cfg["torch_dtype"]]
+    kinds = set(_kinds(mod, cfg))
     return {
-        "table": jax.jit(lambda k: _normal(k, (v, d), 0.02, stored)),
-        "head": jax.jit(lambda k: _normal(k, (d, v), 1.0 / math.sqrt(d), stored)),
-        "layer_weights": jax.jit(lambda k: _layer_weights(cfg, k)),
-        "layer": jax.jit(lambda w, x: _layer(cfg, w, x, control)),
-        "logits": jax.jit(lambda x, w: _matmul(
-            _norm(_arch(cfg), x, 1.0, 0.0), w, control)),
+        "table": jax.jit(lambda k: normal(k, (v, d), 0.02, stored)),
+        "head": jax.jit(lambda k: normal(k, (d, v), 1.0 / math.sqrt(d), stored)),
+        "layer_weights": {
+            kind: jax.jit(functools.partial(mod.layer_weights, cfg, kind=kind))
+            for kind in kinds},
+        "layer": {
+            kind: jax.jit(functools.partial(mod.layer, cfg, control=control,
+                                            kind=kind))
+            for kind in kinds},
+        "logits": jax.jit(lambda x, w: matmul(mod.final_norm(cfg, x), w,
+                                              control)),
     }
 
 
@@ -199,7 +151,7 @@ def forward(cfg: dict, key_seed: int, tokens: np.ndarray, first: int,
     With ``control`` every matrix product runs on float8-rounded operands.
     ``rows_per_block`` bounds the rows one attention call holds.
     """
-    a = _arch(cfg)
+    mod = arch.load(cfg)
     rows = tokens.shape[0]
     block = rows_per_block or rows
     run = _programs(json.dumps(cfg, sort_keys=True), control)
@@ -208,12 +160,13 @@ def forward(cfg: dict, key_seed: int, tokens: np.ndarray, first: int,
         table = run["table"](k_emb)
         x = table[jnp.asarray(tokens)]
         if control:
-            x = _f8(x, -1)
-        head = table.T if a["tied"] else None
+            x = f8(x, -1)
+        head = table.T if mod.tied(cfg) else None
         del table
-        for lk in layer_keys:
-            w = run["layer_weights"](lk)
-            x = jnp.concatenate([run["layer"](w, x[i:i + block])
+        for lk, kind in zip(layer_keys, _kinds(mod, cfg)):
+            w = run["layer_weights"][kind](lk)
+            layer = run["layer"][kind]
+            x = jnp.concatenate([layer(w, x[i:i + block])
                                  for i in range(0, rows, block)])
             del w
         if head is None:

@@ -3,7 +3,8 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its
-configuration is the file the entry of ``configs`` names, its traffic
+configuration is the file the entry of ``configs`` names, its
+architecture the module ``chipbench/arch/<model_type>.py``, its traffic
 mix ``chipbench/traffic/<traffic>.json``, and each metric the reader
 ``chipbench/metrics/<metric>.py``. A run makes the weights on the chip
 from the seed, warms the cell's shapes only, serves ``--seconds`` of the
@@ -47,6 +48,7 @@ ROOT = HERE.parent
 sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(ROOT / "src"))
 
+import arch  # noqa: E402
 import check  # noqa: E402
 import schedule  # noqa: E402
 import serve  # noqa: E402
@@ -112,31 +114,9 @@ def reader(name: str):
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    kind = config["model_type"]
-    if kind == "qwen2":
-        arch = dict(activation="silu", norm="rmsnorm", qkv_bias=True,
-                    mlp_bias=False)
-    elif kind == "starcoder2":
-        arch = dict(activation="gelu", norm="layernorm",
-                    qkv_bias=config["use_bias"], mlp_bias=config["use_bias"])
-    else:
-        raise SystemExit(f"no program configuration for {kind!r}")
-    heads = config["num_attention_heads"]
-    return ModelConfig(
-        name=config["name"], family="dense",
-        num_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"], num_heads=heads,
-        num_kv_heads=config["num_key_value_heads"],
-        head_dim=config.get("head_dim") or config["hidden_size"] // heads,
-        d_ff=config["intermediate_size"], vocab_size=config["vocab_size"],
-        tie_embeddings=config["tie_word_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        max_seq_len=config["max_position_embeddings"],
-        param_dtype=config["torch_dtype"], compute_dtype=config["torch_dtype"],
-        **arch)
+    """The program's ``ModelConfig`` for a configuration file, as the
+    module of its architecture (``arch/<model_type>.py``) makes it."""
+    return arch.load(config).program(config)
 
 
 def trace_task(out: Path, seconds: float):

@@ -131,6 +131,22 @@ def test_planted_fault_is_not_correct(pool, monkeypatch, fault):
     assert not check.verdict(numbers)
 
 
+def test_replica_imbalance_counts_requests_per_replica(pool):
+    config, traffic, (engines, warm, _, _, counter) = pool
+    window = serve.run_window(engines, traffic, 1.5, SEED,
+                              vocab=config["vocab_size"], warm=warm,
+                              counter=counter)
+    served = {0: 0, 1: 0}
+    for c in window.calls:
+        served[c.replica] += c.rows
+    assert min(served.values()) > 0
+    assert sum(served.values()) == len(window.due)
+    run_ = run.Run({}, config, traffic, window, 0.0, None, None)
+    reading = run.reader("replica_imbalance.knee")(run_)
+    assert reading >= 1.0
+    assert reading == max(served.values()) / (sum(served.values()) / 2)
+
+
 def test_new_mix_and_cell_are_found_from_new_files_alone(root, tmp_path):
     shutil.copytree(root, tmp_path / "c", dirs_exist_ok=True)
     new = tmp_path / "c"

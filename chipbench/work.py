@@ -1,6 +1,12 @@
 """Operations and bytes each engine program needs, from a configuration's
 shapes alone.
 
+What a layer holds is the architecture's own count (``arch/<model_type>.py``:
+the weights a token multiplies, the cache one position holds, and, where
+its reads depend on the rows, its own ``prefill`` and ``decode``); this
+file holds the arithmetic every architecture shares, and the counts of a
+dense decoder, which read every layer's weights once per step.
+
 Counted from the published shapes and the cache length in use, never from
 a compiler's cost analysis, so a roofline share reads the same work
 whatever implements it. Matrix products count 2 operations per
@@ -13,6 +19,8 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Dict
+
+import arch
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,43 +41,34 @@ class Work:
 DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
 
-def _dims(cfg: dict):
-    d = cfg["hidden_size"]
-    hq = cfg["num_attention_heads"]
-    hkv = cfg["num_key_value_heads"]
-    hd = cfg.get("head_dim") or d // hq
-    return d, hq, hkv, hd
-
-
 def layer_params(cfg: dict) -> int:
-    """Weights of one decoder layer that a token multiplies (no norms)."""
-    d, hq, hkv, hd = _dims(cfg)
-    f = cfg["intermediate_size"]
-    gated = cfg["hidden_act"] == "silu"  # SwiGLU: gate, up and down
-    attn = d * hd * (hq + 2 * hkv) + hq * hd * d
-    mlp = d * f * (3 if gated else 2)
-    return attn + mlp
+    """Weights of one decoder layer that a token multiplies (no norms), as
+    the architecture's module counts them."""
+    return arch.load(cfg).layer_params(cfg)
+
+
+def kv_bytes_per_position(cfg: dict) -> float:
+    """Bytes of the cache one position holds over all layers, as the
+    architecture's module counts them."""
+    return arch.load(cfg).kv_bytes_per_position(cfg)
 
 
 def weight_bytes(cfg: dict) -> float:
     """Bytes of every weight a forward pass reads: the layers, and the
     output head (the embedding table when it is tied; a lookup of the
     table reads only its rows, so an untied table is not counted)."""
+    mod = arch.load(cfg)
+    if hasattr(mod, "weight_bytes"):
+        return mod.weight_bytes(cfg)
     width = DTYPE_BYTES[cfg["torch_dtype"]]
     head = cfg["vocab_size"] * cfg["hidden_size"]
-    return width * (cfg["num_hidden_layers"] * layer_params(cfg) + head)
-
-
-def kv_bytes_per_position(cfg: dict) -> float:
-    """Bytes of keys and values one position holds over all layers."""
-    _, _, hkv, hd = _dims(cfg)
-    return cfg["num_hidden_layers"] * 2 * hkv * hd * DTYPE_BYTES[cfg["torch_dtype"]]
+    return width * (cfg["num_hidden_layers"] * mod.layer_params(cfg) + head)
 
 
 def _attention_flops(cfg: dict, queries: int, first_key_len: int) -> float:
     """Score and value products of ``queries`` consecutive positions, the
     first of which sees ``first_key_len`` keys (itself included)."""
-    _, hq, _, hd = _dims(cfg)
+    _, hq, _, hd = arch.dims(cfg)
     pairs = queries * first_key_len + queries * (queries - 1) / 2
     return cfg["num_hidden_layers"] * 4.0 * hq * hd * pairs
 
@@ -77,6 +76,9 @@ def _attention_flops(cfg: dict, queries: int, first_key_len: int) -> float:
 def prefill(cfg: dict, rows: int, prompt_len: int) -> Work:
     """The prefill program: ``rows`` prompts of ``prompt_len`` tokens,
     keys and values written for every position, logits for the last."""
+    mod = arch.load(cfg)
+    if hasattr(mod, "prefill"):
+        return mod.prefill(cfg, rows, prompt_len)
     tokens = rows * prompt_len
     flops = (2.0 * cfg["num_hidden_layers"] * layer_params(cfg) * tokens
              + rows * _attention_flops(cfg, prompt_len, 1)
@@ -89,6 +91,9 @@ def decode(cfg: dict, rows: int, prompt_len: int, steps: int) -> Work:
     """The fused decode program: ``steps`` greedy steps after a prompt of
     ``prompt_len``; step ``i`` attends to ``prompt_len + i + 1`` positions
     and reads the weights, the head and that much cache once."""
+    mod = arch.load(cfg)
+    if hasattr(mod, "decode"):
+        return mod.decode(cfg, rows, prompt_len, steps)
     per_token = (2.0 * cfg["num_hidden_layers"] * layer_params(cfg)
                  + 2.0 * cfg["hidden_size"] * cfg["vocab_size"])
     flops = rows * (steps * per_token
